@@ -14,10 +14,11 @@ table's rows) followed by a human-readable summary block per table.
 tables; with ``--engine fast`` given explicitly the run exits non-zero
 if anything forces the vectorized core to degrade to the scalar loop.
 
-Exit codes follow the shared contract in ``repro.cb.cli``: 3 for a
-strict-fast engine fallback, 4 for an armed-SLO breach, and when both
-fire in one run the winner comes from ``EXIT_PRECEDENCE`` (infeasible 2
-beats fallback 3 beats breach 4).
+Exit codes follow the shared contract in ``repro.cb.cli``: 5 when any
+table raised (the other tables still run and print), 3 for a strict-fast
+engine fallback, 4 for an armed-SLO breach, and when several fire in one
+run the winner comes from ``EXIT_PRECEDENCE`` (infeasible 2 beats failed
+5 beats fallback 3 beats breach 4).
 """
 from __future__ import annotations
 
@@ -98,7 +99,7 @@ def main(argv=None) -> None:
     set_default_engine(args.engine)
     # one exit-code contract across both entry points: the precedence
     # table and resolver live in repro.cb.cli
-    from repro.cb.cli import (EXIT_BREACH, EXIT_FALLBACK,
+    from repro.cb.cli import (EXIT_BREACH, EXIT_FAILED, EXIT_FALLBACK,
                               resolve_exit_code)
 
     obs = None
@@ -150,6 +151,7 @@ def main(argv=None) -> None:
     selected = None if args.tables is None else set(args.tables.split(","))
 
     results = []
+    errored = []
     for fn in tables:
         name = fn.__name__.replace("table_", "")
         if selected and name not in selected:
@@ -159,6 +161,7 @@ def main(argv=None) -> None:
             results.append((name, us, rows))
             print(f"{name},{us:.0f},{json.dumps(rows, sort_keys=True)}")
         except Exception as e:  # keep the harness running
+            errored.append(name)
             print(f"{name},-1,{json.dumps({'error': str(e)})}")
 
     if selected is None or "roofline" in selected:
@@ -179,9 +182,16 @@ def main(argv=None) -> None:
             print(f"    {k:36s} {v}")
 
     health = _write_obs(args, obs)
-    if health is not None and health["verdict"] == "breach":
-        sys.exit(resolve_exit_code(EXIT_BREACH))
+    if errored:
+        print(f"tables that raised: {', '.join(errored)}", file=sys.stderr)
+    breach = (EXIT_BREACH if health is not None
+              and health["verdict"] == "breach" else 0)
+    code = resolve_exit_code(EXIT_FAILED if errored else 0, breach)
+    if code:
+        sys.exit(code)
 
 
 if __name__ == "__main__":
+    from repro.core.timing import use_compile_cache
+    use_compile_cache()
     main()

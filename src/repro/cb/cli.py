@@ -24,11 +24,18 @@ and names the reason instead of silently falling back:
     PYTHONPATH=src python -m repro.cb.cli --commits 6 \
         --deadline 900 --budget 0.25 --seed 1
 
+A benchmark of a realtime suite (``--suite kernels``) that produced no
+result — its program raised, ran out of memory, or timed out — is listed
+under ``failed`` in the summary and makes the run exit with code 5.  In
+the simulated suites a failed benchmark is a modeled outcome (a workload
+the platform cannot run, or one past its timeout): it is listed, and the
+exit code stays 0.
+
 Failure conditions can co-occur (a multi-provider run may hit an
 infeasible plan on one provider, a strict-fast fallback on another, and
 an SLO breach overall); the process exit code is then resolved
-deterministically by `EXIT_PRECEDENCE`: infeasible (2) beats engine
-fallback (3) beats SLO breach (4).
+deterministically by `EXIT_PRECEDENCE`: infeasible (2) beats failed
+benchmarks (5) beats engine fallback (3) beats SLO breach (4).
 """
 from __future__ import annotations
 
@@ -44,15 +51,18 @@ from repro.cb.registry import SyntheticSuite, get_suite
 EXIT_INFEASIBLE = 2
 EXIT_FALLBACK = 3       # `--engine fast` was explicit but the run degraded
 EXIT_BREACH = 4         # `--slo` was armed and an objective breached
+EXIT_FAILED = 5         # a benchmark of a realtime suite produced no result
 
 #: Deterministic winner when failure conditions co-occur, strongest
 #: first.  Infeasible (2) outranks everything: the planner refused, so
-#: nothing downstream is meaningful.  Fallback (3) outranks breach (4):
+#: nothing downstream is meaningful.  Failed benchmarks (5) come next:
+#: some measurements do not exist at all, which is worse than existing
+#: numbers taken on the wrong core.  Fallback (3) outranks breach (4):
 #: a strict `--engine fast` run that degraded produced its numbers on
 #: the wrong core, so an SLO verdict measured on them is already
 #: suspect.  Both entry points (`repro.cb.cli`, `benchmarks.run`)
 #: resolve through this table from a single return site.
-EXIT_PRECEDENCE = (EXIT_INFEASIBLE, EXIT_FALLBACK, EXIT_BREACH)
+EXIT_PRECEDENCE = (EXIT_INFEASIBLE, EXIT_FAILED, EXIT_FALLBACK, EXIT_BREACH)
 
 
 def resolve_exit_code(*codes: int) -> int:
@@ -284,8 +294,10 @@ def main(argv=None) -> int:
                     parallelism=args.parallelism, seed=args.seed,
                     max_staleness=args.max_staleness,
                     adaptive=args.adaptive, engine=args.engine)
-                rep = Pipeline(get_suite(args.suite), cfg,
-                               history=history).run_stream(commits)
+                suite = get_suite(args.suite)
+                rep = Pipeline(suite, cfg, history=history).run_stream(commits)
+                if rep.failed and suite.realtime:
+                    code = resolve_exit_code(code, EXIT_FAILED)
                 summary = {
                     "suite": args.suite, "provider": provider, "mode": mode,
                     "commits": len(rep.commits),
@@ -294,6 +306,7 @@ def main(argv=None) -> int:
                     "wall_min": round(rep.total_wall_seconds / 60.0, 2),
                     "cache_hits": rep.cache_hits,
                     "flagged": rep.total_flagged,
+                    "failed": rep.failed,
                     "events": [str(e) for e in rep.events],
                 }
                 if drift is not None:
@@ -328,4 +341,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core.timing import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

@@ -134,6 +134,8 @@ class CommitRun:
     billed_seconds: float
     cost_dollars: float
     wall_seconds: float
+    failed: List[str] = field(default_factory=list)  # benchmarks the
+    #                                 engine ran but that produced no result
 
 
 @dataclass
@@ -161,6 +163,11 @@ class PipelineReport:
     @property
     def total_flagged(self) -> int:
         return sum(len(c.flagged) for c in self.commits)
+
+    @property
+    def failed(self) -> List[str]:
+        """`commit_id:benchmark` for every benchmark that failed."""
+        return [f"{c.commit_id}:{b}" for c in self.commits for b in c.failed]
 
     def commit(self, commit_id: str) -> CommitRun:
         return next(c for c in self.commits if c.commit_id == commit_id)
@@ -220,6 +227,7 @@ class Pipeline:
         billed = 0.0
         cost = 0.0
         wall = 0.0
+        failed: List[str] = []
         changes: Dict[str, ChangeResult] = {}
         if work.to_measure:
             result = self.suite.run(
@@ -235,6 +243,7 @@ class Pipeline:
             billed = float(sum(rep.billed_seconds))
             cost = rep.cost_dollars
             wall = rep.wall_seconds
+            failed = list(rep.failed_benchmarks)
         from repro.obs import get_obs
         obs = get_obs()
         if obs is not None and obs.enabled:
@@ -247,7 +256,8 @@ class Pipeline:
         self._obs_clock += wall
         return self._finalize(commit, work, changes, meter.invocations,
                               meter.billed_s, invocations=invocations,
-                              billed=billed, cost=cost, wall=wall)
+                              billed=billed, cost=cost, wall=wall,
+                              failed=failed)
 
     # ------------------------------------------------------------ service
     def submit_stream(self, commits: List[Commit], service, *,
@@ -320,7 +330,8 @@ class Pipeline:
                 r.benchmark_invocations, r.benchmark_billed_s,
                 invocations=r.invocations, billed=r.billed_seconds,
                 cost=r.cost_dollars, wall=r.end_s - r.start_s,
-                fully_measured=not r.preempted))
+                fully_measured=not r.preempted,
+                failed=list(r.failed_benchmarks)))
         events = RegressionDetector(self.cfg.detector).scan(
             self.history, provider=self.cfg.provider, mode=self.cfg.mode)
         return PipelineReport(
@@ -442,7 +453,8 @@ class Pipeline:
                   run_changes: Dict[str, ChangeResult],
                   meter_inv: Dict[str, int], meter_billed: Dict[str, float],
                   *, invocations: int, billed: float, cost: float,
-                  wall: float, fully_measured: bool = True) -> CommitRun:
+                  wall: float, fully_measured: bool = True,
+                  failed: List[str] = ()) -> CommitRun:
         """Everything after the measurement: cache fills, history records,
         the CommitRun.  Called inline right after the suite run, or at
         service delivery time (causally ordered per tenant).
@@ -529,7 +541,7 @@ class Pipeline:
             cache_hits=work.cache_hits, skipped=sel.skipped, changes=changes,
             flagged=sorted(b for b, c in changes.items() if c.changed),
             invocations=invocations, billed_seconds=billed,
-            cost_dollars=cost, wall_seconds=wall)
+            cost_dollars=cost, wall_seconds=wall, failed=sorted(failed))
 
 
 def _job_seed(seed: int, commit: Commit) -> int:

@@ -38,9 +38,14 @@ class BenchmarkSuite:
     pipeline uses one to meter per-benchmark invocations and billed
     seconds); implementations must compose it with any observer of their
     own (e.g. the adaptive controller) via `FanoutObserver`.
+
+    `realtime` suites execute real programs: a benchmark that fails there
+    is a failure of the run, where a simulated suite's failed benchmark is
+    a modeled outcome.
     """
 
     name: str = ""
+    realtime: bool = False
 
     def benchmark_names(self) -> List[str]:
         raise NotImplementedError

@@ -1,18 +1,38 @@
 """Timing source for real (non-simulated) benchmark execution.
 
-The TPU/JAX adaptation of Go's benchmark harness (DESIGN.md §3): a jitted
-program is timed around block_until_ready with perf_counter_ns, after a
-calibration phase that picks an inner-repeat count so one measurement takes
-at least ``min_measure_s`` (Go's -benchtime analogue).  Compile ("cold
-start") time is measured separately.
+The TPU/JAX adaptation of Go's benchmark harness: a jitted program is
+timed around block_until_ready with perf_counter, after a calibration
+phase that picks an inner-repeat count so one measurement takes at least
+``min_measure_s`` (Go's -benchtime analogue).  Compile ("cold start") time
+is measured separately.
 """
 from __future__ import annotations
 
+import os
 import time
 from dataclasses import dataclass
-from typing import Callable, Optional
+from pathlib import Path
+from typing import Callable
 
 import jax
+
+#: Persistent compile cache of a checkout when the environment names none.
+#: A fixed path: the directory is part of what a cache entry is found by.
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.  ``JAX_COMPILATION_CACHE_DIR``, where set, is
+    left to JAX; otherwise the cache lives in `CHECKOUT_CACHE_DIR`.  Every
+    program is cached, however quickly it compiled.  Call before the
+    first compilation: JAX fixes the cache when it first compiles."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(CHECKOUT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
 
 
 @dataclass

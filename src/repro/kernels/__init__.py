@@ -1,3 +1,2 @@
 from repro.kernels.ops import flash_attention, ssd_scan  # noqa: F401
-from repro.kernels.stats_boot import (  # noqa: F401
-    HAS_JAX as HAS_JAX_STATS, bootstrap_median_ci_batch_jax)
+from repro.kernels.stats_boot import bootstrap_median_ci_batch_jax  # noqa: F401

@@ -36,38 +36,47 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state_ref, *,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
+    hp = jax.lax.Precision.HIGHEST
     x = x_ref[0, 0].astype(jnp.float32)                     # [Q, P]
-    dt = dt_ref[0, 0].astype(jnp.float32)                   # [Q]
-    a = a_ref[0].astype(jnp.float32)                        # scalar (<0)
+    dt = dt_ref[0, 0].astype(jnp.float32)                   # [Q, 1]
+    a = a_ref[pl.program_id(1)]                             # scalar (<0), SMEM
     Bm = b_ref[0, 0].astype(jnp.float32)                    # [Q, N]
     Cm = c_ref[0, 0].astype(jnp.float32)                    # [Q, N]
 
     # zero out padded tail rows (dt=0 is an exact no-op)
-    t_pos = ic * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)[:, 0]
+    t_pos = ic * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
     dt = jnp.where(t_pos < seq_valid, dt, 0.0)
 
-    da = dt * a                                             # [Q] <= 0
-    cum = jnp.cumsum(da)                                    # [Q]
-    total = cum[-1]
+    # Mosaic has no cumsum: the inclusive prefix sum is a lower-triangular
+    # matmul, taken once as a column and once (transposed lhs) as a row so
+    # every vector stays 2-D
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = ii >= jj
+    da = dt * a                                             # [Q, 1] <= 0
+    cum = jax.lax.dot(causal.astype(jnp.float32), da, precision=hp,
+                      preferred_element_type=jnp.float32)   # [Q, 1]
+    cum_row = jax.lax.dot_general(
+        da, (ii <= jj).astype(jnp.float32), (((0,), (0,)), ((), ())),
+        precision=hp, preferred_element_type=jnp.float32)   # [1, Q]
+    total = jnp.sum(da, axis=0, keepdims=True)              # [1, 1]
 
     # ---- intra-chunk --------------------------------------------------
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [Qi,Qj]
-    decay = jnp.exp(cum[:, None] - cum[None, :])
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    att = jnp.where(ii >= jj, cb * decay * dt[None, :], 0.0)
-    y = jax.lax.dot(att, x, preferred_element_type=jnp.float32)  # [Q,P]
+    att = jnp.where(causal, cb * jnp.exp(cum - cum_row), 0.0)
+    # dt_j scales column j of att, i.e. row j of x
+    y = jax.lax.dot(att, x * dt, preferred_element_type=jnp.float32)  # [Q,P]
 
     # ---- inter-chunk ---------------------------------------------------
     state = state_ref[...]                                  # [P, N]
     ch = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [Q,P]
-    y = y + jnp.exp(cum)[:, None] * ch
+    y = y + jnp.exp(cum) * ch
 
     # ---- state update ---------------------------------------------------
-    w = jnp.exp(total - cum) * dt                           # [Q]
-    s_new = jax.lax.dot_general(x, Bm * w[:, None], (((0,), (0,)), ((), ())),
+    w = jnp.exp(total - cum) * dt                           # [Q, 1]
+    s_new = jax.lax.dot_general(x, Bm * w, (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [P,N]
     state_ref[...] = state * jnp.exp(total) + s_new
 
@@ -103,8 +112,8 @@ def ssd_scan_hmajor(x, dt, A, B_in, C_in, *, chunk=DEFAULT_CHUNK,
         grid=(B, H, n_chunks),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h // hg, c, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h // hg, c, 0)),
         ],
@@ -118,7 +127,7 @@ def ssd_scan_hmajor(x, dt, A, B_in, C_in, *, chunk=DEFAULT_CHUNK,
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, B_in, C_in)
+    )(x, dt[..., None], A.astype(jnp.float32), B_in, C_in)
     return y[:, :, :S], state
 
 

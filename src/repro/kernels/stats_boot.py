@@ -11,43 +11,31 @@ the cached ``(n_boot, n)`` index matrix from `stats._boot_draw`, so both
 backends bootstrap the *same* resamples.  Numerical results agree with the
 NumPy path to float tolerance (XLA defaults to float32 unless x64 is
 enabled); bit-for-bit replay of seed behavior stays the NumPy path's job.
-
-Import of this module never requires jax: `HAS_JAX` gates availability and
-`bootstrap_median_ci_batch_jax` raises a clear error when the accelerator
-path was requested without jax installed.
 """
 from __future__ import annotations
 
 import functools
 from typing import Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-try:
-    import jax
-    import jax.numpy as jnp
-    HAS_JAX = True
-except Exception:                                   # pragma: no cover
-    jax = None
-    jnp = None
-    HAS_JAX = False
 
+@functools.partial(jax.jit, static_argnames=("lo_idx", "hi_idx"))
+def _boot_median_ci_block(block, idx, *, lo_idx: int, hi_idx: int):
+    """(k, n) same-length diff block + (n_boot, n) index matrix ->
+    (med, lo, hi), each (k,).
 
-if HAS_JAX:
-    @functools.partial(jax.jit, static_argnames=("lo_idx", "hi_idx"))
-    def _boot_median_ci_block(block, idx, *, lo_idx: int, hi_idx: int):
-        """(k, n) same-length diff block + (n_boot, n) index matrix ->
-        (med, lo, hi), each (k,).
-
-        One fused program: gather every resample, per-row medians, then
-        the conservative outward quantiles as order statistics of the
-        sorted bootstrap-median distribution (`lo_idx`/`hi_idx` replicate
-        ``np.quantile(..., method="lower"/"higher")``)."""
-        resamples = block[:, idx]                   # (k, n_boot, n)
-        boots = jnp.median(resamples, axis=2)       # (k, n_boot)
-        boots = jnp.sort(boots, axis=1)
-        return (jnp.median(block, axis=1),
-                boots[:, lo_idx], boots[:, hi_idx])
+    One fused program: gather every resample, per-row medians, then
+    the conservative outward quantiles as order statistics of the
+    sorted bootstrap-median distribution (`lo_idx`/`hi_idx` replicate
+    ``np.quantile(..., method="lower"/"higher")``)."""
+    resamples = block[:, idx]                   # (k, n_boot, n)
+    boots = jnp.median(resamples, axis=2)       # (k, n_boot)
+    boots = jnp.sort(boots, axis=1)
+    return (jnp.median(block, axis=1),
+            boots[:, lo_idx], boots[:, hi_idx])
 
 
 def bootstrap_median_ci_batch_jax(arrays: Sequence[np.ndarray], *,
@@ -58,10 +46,7 @@ def bootstrap_median_ci_batch_jax(arrays: Sequence[np.ndarray], *,
 
     Same grouping-by-length batching and the same cached index matrices;
     returns (med, lo, hi) NumPy float arrays aligned with `arrays` (NaN
-    for empty inputs).  Requires jax."""
-    if not HAS_JAX:
-        raise RuntimeError("jax backend requested but jax is not available; "
-                           "use the default NumPy statistics path")
+    for empty inputs)."""
     from repro.core.stats import _boot_draw
 
     k = len(arrays)

@@ -11,23 +11,15 @@ import jax
 
 
 def axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,) * n`` where the installed jax has explicit axis
-    types (>= 0.5), ``{}`` otherwise — older jax's implicit behaviour *is*
-    Auto, so meshes built either way shard identically."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    """``axis_types=(Auto,) * n``: every mesh axis sharded automatically."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-less mesh (shapes/names only) across jax versions: newer jax
-    takes ``(shape, axis_names)`` like ``make_mesh``; older jax takes a
-    single tuple of ``(name, size)`` pairs."""
+    """Device-less mesh (shapes/names only)."""
     from jax.sharding import AbstractMesh
-    if hasattr(jax.sharding, "AxisType"):
-        return AbstractMesh(tuple(shape), tuple(axes),
-                            **axis_types_kwargs(len(axes)))
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes),
+                        **axis_types_kwargs(len(axes)))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -217,13 +217,8 @@ def moe_ffn_sharded(x, params, moe: MoEConfig, plan, gather_mode="auto"):
             aux = jax.lax.pmean(aux, model_ax)
         return y.reshape(B, S, D), aux
 
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-    else:                        # pre-0.5 jax: experimental API, check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-        fn = _shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(x, params["router"], params["w_gate"], params["w_up"],
               params["w_down"])
 

@@ -47,10 +47,6 @@ def test_restore_missing_leaf_raises(tmp_path):
         ckpt.restore(str(tmp_path), 1, {"a": jnp.zeros(2), "b": jnp.zeros(2)})
 
 
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="installed jax predates jax.sharding.AxisType (explicit axis "
-           "types); sharded-restore path needs it")
 def test_restore_into_new_sharding(tmp_path):
     """elastic rescale: restore device_puts onto target shardings."""
     from jax.sharding import NamedSharding, PartitionSpec as P

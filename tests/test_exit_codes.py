@@ -1,11 +1,12 @@
 """Exit-code contract: deterministic precedence when failures co-occur.
 
-The pipeline CLI and the benchmark harness can hit three failure
-conditions in one run — infeasible plan (2), strict-fast engine
-fallback (3), armed-SLO breach (4) — and historically whichever check
-happened to run first won.  The contract is now explicit: all
-conditions are evaluated, the winner comes from `EXIT_PRECEDENCE`
-(2 beats 3 beats 4), and both entry points return from one resolver.
+The pipeline CLI and the benchmark harness can hit four failure
+conditions in one run — infeasible plan (2), failed benchmarks (5),
+strict-fast engine fallback (3), armed-SLO breach (4) — and
+historically whichever check happened to run first won.  The contract
+is now explicit: all conditions are evaluated, the winner comes from
+`EXIT_PRECEDENCE` (2 beats 5 beats 3 beats 4), and both entry points
+return from one resolver.
 Each pairwise collision is pinned here, at the resolver and end-to-end
 through `repro.cb.cli`.
 """
@@ -13,16 +14,17 @@ import json
 
 import pytest
 
-from repro.cb.cli import (EXIT_BREACH, EXIT_FALLBACK, EXIT_INFEASIBLE,
-                          EXIT_PRECEDENCE, resolve_exit_code)
+from repro.cb.cli import (EXIT_BREACH, EXIT_FAILED, EXIT_FALLBACK,
+                          EXIT_INFEASIBLE, EXIT_PRECEDENCE, resolve_exit_code)
 from repro.cb.cli import main as cli_main
 
 
 # ------------------------------------------------------------ resolver
 
 def test_precedence_table_is_the_documented_contract():
-    assert EXIT_PRECEDENCE == (EXIT_INFEASIBLE, EXIT_FALLBACK, EXIT_BREACH)
-    assert EXIT_PRECEDENCE == (2, 3, 4)
+    assert EXIT_PRECEDENCE == (EXIT_INFEASIBLE, EXIT_FAILED, EXIT_FALLBACK,
+                               EXIT_BREACH)
+    assert EXIT_PRECEDENCE == (2, 5, 3, 4)
 
 
 @pytest.mark.parametrize("pair, winner", [
@@ -37,6 +39,16 @@ def test_pairwise_collisions_resolve_by_precedence(pair, winner):
     assert resolve_exit_code(a, b) == winner
     assert resolve_exit_code(b, a) == winner
     assert resolve_exit_code(0, a, 0, b) == winner
+
+
+@pytest.mark.parametrize("other, winner", [
+    (EXIT_INFEASIBLE, EXIT_INFEASIBLE),
+    (EXIT_FALLBACK, EXIT_FAILED),
+    (EXIT_BREACH, EXIT_FAILED),
+])
+def test_failed_benchmarks_rank_below_infeasible_only(other, winner):
+    assert resolve_exit_code(EXIT_FAILED, other) == winner
+    assert resolve_exit_code(other, EXIT_FAILED) == winner
 
 
 def test_three_way_collision_and_identities():

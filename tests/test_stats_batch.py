@@ -182,9 +182,6 @@ def test_streaming_results_batch_query():
 
 # ------------------------------------------------------------ jax kernel
 def test_jax_kernel_agrees_with_numpy():
-    from repro.kernels.stats_boot import HAS_JAX
-    if not HAS_JAX:
-        pytest.skip("jax unavailable")
     rng = np.random.default_rng(0)
     arrays = [rng.normal(0, 1, n) for n in (45, 45, 128, 31, 10)]
     m0, l0, h0 = bootstrap_median_ci_batch(arrays, seed=3)
