@@ -24,6 +24,11 @@ and names the reason instead of silently falling back:
     PYTHONPATH=src python -m repro.cb.cli --commits 6 \
         --deadline 900 --budget 0.25 --seed 1
 
+With ``--suite kernels`` the programs run for real: ``--trace OUT`` then
+records a ``jax.profiler`` trace into the directory OUT (with a Perfetto
+trace beside it), where the program's spans (``repro.obs.spans``) lie on
+the same clock as the device's program executions.
+
 A benchmark of a realtime suite (``--suite kernels``) that produced no
 result — its program raised, ran out of memory, or timed out — is listed
 under ``failed`` in the summary and makes the run exit with code 5.  In
@@ -187,6 +192,41 @@ def _run_service(args, history, providers, modes) -> int:
     return resolve_exit_code(*codes)
 
 
+def _run_inline(args, history, providers, modes, commits, drift) -> int:
+    """The inline path: one pipeline per (provider, mode) over the commit
+    stream, one summary line each; returns the resolved exit code."""
+    code = 0
+    for provider in providers:
+        for mode in modes:
+            cfg = PipelineConfig(
+                suite=args.suite, provider=provider, mode=mode,
+                n_calls=args.n_calls, repeats_per_call=args.repeats,
+                parallelism=args.parallelism, seed=args.seed,
+                max_staleness=args.max_staleness,
+                adaptive=args.adaptive, engine=args.engine)
+            suite = get_suite(args.suite)
+            rep = Pipeline(suite, cfg, history=history).run_stream(commits)
+            if rep.failed and suite.realtime:
+                code = resolve_exit_code(code, EXIT_FAILED)
+            summary = {
+                "suite": args.suite, "provider": provider, "mode": mode,
+                "commits": len(rep.commits),
+                "invocations": rep.total_invocations,
+                "cost_usd": round(rep.total_cost, 4),
+                "wall_min": round(rep.total_wall_seconds / 60.0, 2),
+                "cache_hits": rep.cache_hits,
+                "flagged": rep.total_flagged,
+                "failed": rep.failed,
+                "events": [str(e) for e in rep.events],
+            }
+            if drift is not None:
+                summary["drift_ground_truth"] = (
+                    f"{drift.benchmark} +{drift.total_pct:.1f}% over "
+                    f"commits {drift.start}..{drift.end}")
+            print(json.dumps(summary, sort_keys=True))
+    return code
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--suite", default="synthetic")
@@ -224,10 +264,16 @@ def main(argv=None) -> int:
                     help="history-store JSONL path (appended across runs)")
     ap.add_argument("--sqlite", default=None,
                     help="also export the history to this SQLite file")
-    ap.add_argument("--trace", default=None, metavar="OUT.json",
-                    help="record a virtual-time trace and write it as "
-                         "Chrome trace_event JSON (open in Perfetto / "
-                         "chrome://tracing)")
+    ap.add_argument("--trace", default=None, metavar="OUT",
+                    help="simulated suites: record a virtual-time trace "
+                         "and write it to the file OUT as Chrome "
+                         "trace_event JSON (open in Perfetto / "
+                         "chrome://tracing).  --suite kernels: record a "
+                         "jax.profiler trace into the directory OUT, with "
+                         "a Perfetto trace beside it; it holds the "
+                         "program's spans (commit, engine, duet pair, "
+                         "timing-harness rounds) beside the device's "
+                         "program executions")
     ap.add_argument("--metrics-out", default=None, metavar="OUT.json",
                     help="write the metrics registry snapshot "
                          "(render with `python -m repro.obs.report`)")
@@ -250,8 +296,10 @@ def main(argv=None) -> int:
     from repro.faas.engine_vec import set_default_engine
     set_default_engine(args.engine)
 
+    # a realtime suite traces on the profiler's clock, not the virtual one
+    profile_dir = args.trace if args.suite == "kernels" else None
     obs = None
-    if args.slo or args.trace or args.metrics_out:
+    if args.slo or args.metrics_out or (args.trace and profile_dir is None):
         from repro.obs import Observability, load_slos, set_obs
         if args.slo:
             specs = None if args.slo is True else load_slos(args.slo)
@@ -279,48 +327,26 @@ def main(argv=None) -> int:
     providers = (["local"] if args.suite == "kernels"
                  else args.providers.split(","))
 
-    code = 0
     if service_mode:
         if args.adaptive:
             ap.error("--adaptive is an inline-run feature; drop it in "
                      "service mode")
         code = _run_service(args, history, providers, modes)
+    elif profile_dir is not None:
+        import jax
+        with jax.profiler.trace(profile_dir, create_perfetto_trace=True):
+            code = _run_inline(args, history, providers, modes, commits,
+                               drift)
+        print(f"trace: profiler trace -> {profile_dir}")
     else:
-        for provider in providers:
-            for mode in modes:
-                cfg = PipelineConfig(
-                    suite=args.suite, provider=provider, mode=mode,
-                    n_calls=args.n_calls, repeats_per_call=args.repeats,
-                    parallelism=args.parallelism, seed=args.seed,
-                    max_staleness=args.max_staleness,
-                    adaptive=args.adaptive, engine=args.engine)
-                suite = get_suite(args.suite)
-                rep = Pipeline(suite, cfg, history=history).run_stream(commits)
-                if rep.failed and suite.realtime:
-                    code = resolve_exit_code(code, EXIT_FAILED)
-                summary = {
-                    "suite": args.suite, "provider": provider, "mode": mode,
-                    "commits": len(rep.commits),
-                    "invocations": rep.total_invocations,
-                    "cost_usd": round(rep.total_cost, 4),
-                    "wall_min": round(rep.total_wall_seconds / 60.0, 2),
-                    "cache_hits": rep.cache_hits,
-                    "flagged": rep.total_flagged,
-                    "failed": rep.failed,
-                    "events": [str(e) for e in rep.events],
-                }
-                if drift is not None:
-                    summary["drift_ground_truth"] = (
-                        f"{drift.benchmark} +{drift.total_pct:.1f}% over "
-                        f"commits {drift.start}..{drift.end}")
-                print(json.dumps(summary, sort_keys=True))
+        code = _run_inline(args, history, providers, modes, commits, drift)
     if args.history:
         print(f"history: {len(history)} records -> {args.history}")
     if args.sqlite:
         history.to_sqlite(args.sqlite)
         print(f"sqlite export -> {args.sqlite}")
     if obs is not None:
-        if args.trace:
+        if args.trace and profile_dir is None:
             obs.export_trace(args.trace)
             print(f"trace: {len(obs.tracer)} events -> {args.trace}")
         if args.metrics_out:

@@ -27,6 +27,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.cb.history import HistoryRecord, HistoryStore
+from repro.obs.spans import span
 
 # 99% two-sided normal quantile: converts a stored CI half-width to an SE
 _Z99 = 2.5758293035489004
@@ -158,18 +159,20 @@ class RegressionDetector:
              mode: Optional[str] = None) -> List[RegressionEvent]:
         """Scan every benchmark series; a store holding several providers /
         modes is scanned per (suite, provider, mode) group so unrelated
-        measurement series never sum into one window."""
+        measurement series never sum into one window.  A span
+        (``cb.detect``) on the profiler's clock."""
         events: List[RegressionEvent] = []
-        for b in history.benchmarks():
-            groups: dict = {}
-            for r in history.series(b, provider=provider, mode=mode):
-                if r.source == "baseline":
-                    continue
-                groups.setdefault((r.suite, r.provider, r.mode),
-                                  []).append(record_to_point(r))
-            for pts in groups.values():
-                ev = self.scan_series(b, pts)
-                if ev is not None:
-                    events.append(ev)
+        with span("cb.detect"):
+            for b in history.benchmarks():
+                groups: dict = {}
+                for r in history.series(b, provider=provider, mode=mode):
+                    if r.source == "baseline":
+                        continue
+                    groups.setdefault((r.suite, r.provider, r.mode),
+                                      []).append(record_to_point(r))
+                for pts in groups.values():
+                    ev = self.scan_series(b, pts)
+                    if ev is not None:
+                        events.append(ev)
         events.sort(key=lambda e: -e.score)
         return events

@@ -25,6 +25,7 @@ for changes no single pairwise comparison could flag.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -37,6 +38,7 @@ from repro.cb.history import (HistoryRecord, HistoryStore, SOURCE_BASELINE,
                               SOURCE_CACHE, SOURCE_RUN, SOURCE_SKIP)
 from repro.cb.registry import BenchmarkSuite, _commit_seed, get_suite
 from repro.cb.select import BenchmarkSelector, SelectorConfig
+from repro.obs.spans import span
 
 MODES = ("full", "selective", "selective_cached")
 
@@ -216,9 +218,20 @@ class Pipeline:
 
     # ------------------------------------------------------------- commit
     def run_commit(self, commit: Commit) -> Optional[CommitRun]:
-        """Process one commit inline; returns None for the baseline."""
+        """Process one commit inline; returns None for the baseline.
+
+        A realtime suite's commit is traced on the profiler's clock
+        (``repro.obs.spans``: ``cb.commit`` around ``cb.prepare``,
+        ``cb.suite_run`` and ``cb.finalize``); a simulated suite's, as one
+        span on the virtual clock of the process's tracer."""
+        lane = span if self.suite.realtime else _no_span
+        with lane("cb.commit", commit=commit.commit_id):
+            return self._run_commit(commit, lane)
+
+    def _run_commit(self, commit: Commit, lane) -> Optional[CommitRun]:
         cfg = self.cfg
-        work = self._prepare(commit)
+        with lane("cb.prepare"):
+            work = self._prepare(commit)
         if work is None:
             return None
 
@@ -230,13 +243,15 @@ class Pipeline:
         failed: List[str] = []
         changes: Dict[str, ChangeResult] = {}
         if work.to_measure:
-            result = self.suite.run(
-                work.to_measure, work.run_commit, provider=cfg.provider,
-                n_calls=cfg.n_calls, repeats_per_call=cfg.repeats_per_call,
-                parallelism=cfg.parallelism, memory_mb=cfg.memory_mb,
-                seed=cfg.seed, min_results=cfg.min_results,
-                adaptive=cfg.adaptive, chaos=cfg.chaos, observer=meter,
-                engine=cfg.engine)
+            with lane("cb.suite_run"):
+                result = self.suite.run(
+                    work.to_measure, work.run_commit, provider=cfg.provider,
+                    n_calls=cfg.n_calls,
+                    repeats_per_call=cfg.repeats_per_call,
+                    parallelism=cfg.parallelism, memory_mb=cfg.memory_mb,
+                    seed=cfg.seed, min_results=cfg.min_results,
+                    adaptive=cfg.adaptive, chaos=cfg.chaos, observer=meter,
+                    engine=cfg.engine)
             changes = result.changes
             rep = result.report
             invocations = len(rep.billed_seconds)
@@ -244,20 +259,22 @@ class Pipeline:
             cost = rep.cost_dollars
             wall = rep.wall_seconds
             failed = list(rep.failed_benchmarks)
-        from repro.obs import get_obs
-        obs = get_obs()
-        if obs is not None and obs.enabled:
-            obs.tracer.span(
-                commit.commit_id, cat="commit", ts=self._obs_clock,
-                dur=wall, pid=f"cb:{cfg.provider}", tid="commits",
-                args={"measured": len(work.to_measure),
-                      "cache_hits": len(work.cache_hits),
-                      "invocations": invocations, "cost_usd": cost})
-        self._obs_clock += wall
-        return self._finalize(commit, work, changes, meter.invocations,
-                              meter.billed_s, invocations=invocations,
-                              billed=billed, cost=cost, wall=wall,
-                              failed=failed)
+        if not self.suite.realtime:
+            from repro.obs import get_obs
+            obs = get_obs()
+            if obs is not None and obs.enabled:
+                obs.tracer.span(
+                    commit.commit_id, cat="commit", ts=self._obs_clock,
+                    dur=wall, pid=f"cb:{cfg.provider}", tid="commits",
+                    args={"measured": len(work.to_measure),
+                          "cache_hits": len(work.cache_hits),
+                          "invocations": invocations, "cost_usd": cost})
+            self._obs_clock += wall
+        with lane("cb.finalize"):
+            return self._finalize(commit, work, changes, meter.invocations,
+                                  meter.billed_s, invocations=invocations,
+                                  billed=billed, cost=cost, wall=wall,
+                                  failed=failed)
 
     # ------------------------------------------------------------ service
     def submit_stream(self, commits: List[Commit], service, *,
@@ -542,6 +559,10 @@ class Pipeline:
             flagged=sorted(b for b, c in changes.items() if c.changed),
             invocations=invocations, billed_seconds=billed,
             cost_dollars=cost, wall_seconds=wall, failed=sorted(failed))
+
+
+def _no_span(name: str, **meta) -> contextlib.nullcontext:
+    return contextlib.nullcontext()
 
 
 def _job_seed(seed: int, commit: Commit) -> int:

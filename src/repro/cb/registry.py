@@ -20,6 +20,7 @@ from repro.core.results import analyze
 from repro.faas.engine import (EngineConfig, EngineObserver, EngineReport,
                                ExecutionEngine, FanoutObserver)
 from repro.cb.commits import Commit
+from repro.obs.spans import span
 
 
 @dataclass
@@ -87,7 +88,7 @@ def run_plan(backend, plan, *, parallelism: int, seed: int,
     default); wave-eligible observers (e.g. the pipeline's benchmark
     meter) ride the vectorized path, while adaptive-controller runs
     stream through the scalar loop (the controller injects work
-    mid-flight)."""
+    mid-flight).  The analysis is a span (``cb.analyze``)."""
     from repro.faas.engine_vec import make_engine
     eng = make_engine(backend, EngineConfig(parallelism=parallelism),
                       engine=engine)
@@ -99,10 +100,12 @@ def run_plan(backend, plan, *, parallelism: int, seed: int,
         obs = controller if observer is None \
             else FanoutObserver([controller, observer])
     report = eng.run(plan, observer=obs)
-    if controller is not None:
-        changes = controller.analyzer.analyze()
-    else:
-        changes = analyze(report.pairs, seed=seed, min_results=min_results)
+    with span("cb.analyze"):
+        if controller is not None:
+            changes = controller.analyzer.analyze()
+        else:
+            changes = analyze(report.pairs, seed=seed,
+                              min_results=min_results)
     return SuiteRunResult(report=report, changes=changes)
 
 

@@ -14,6 +14,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.obs.spans import span
+
 
 @dataclass
 class DuetPair:
@@ -48,11 +50,14 @@ class DuetRunnable:
 
     def run_pair(self, order: Tuple[str, str]) -> Tuple[float, float]:
         """Run one duet pair in the given version order; returns
-        (v1_seconds, v2_seconds) regardless of execution order."""
-        self.ensure_setup()
-        results = {}
-        for v in order:
-            results[v] = self.v1() if v == "v1" else self.v2()
+        (v1_seconds, v2_seconds) regardless of execution order.  The pair
+        and each version are spans (``duet.pair``, ``duet.v1/<name>``)."""
+        with span("duet.pair"):
+            self.ensure_setup()
+            results = {}
+            for v in order:
+                with span(f"duet.{v}/{self.name}"):
+                    results[v] = self.v1() if v == "v1" else self.v2()
         return results["v1"], results["v2"]
 
 

@@ -3,8 +3,10 @@
 The TPU/JAX adaptation of Go's benchmark harness: a jitted program is
 timed around block_until_ready with perf_counter, after a calibration
 phase that picks an inner-repeat count so one measurement takes at least
-``min_measure_s`` (Go's -benchtime analogue).  Compile ("cold start") time
-is measured separately.
+``min_measure_s`` (Go's -benchtime analogue).  The warm-up call and each
+calibration round are spans on the profiler's clock (``timing.warmup``,
+``timing.round``; ``repro.obs.spans``), the last round of a call being the
+measured one.
 """
 from __future__ import annotations
 
@@ -15,6 +17,8 @@ from pathlib import Path
 from typing import Callable
 
 import jax
+
+from repro.obs.spans import span
 
 #: Persistent compile cache of a checkout when the environment names none.
 #: A fixed path: the directory is part of what a cache entry is found by.
@@ -39,8 +43,6 @@ def use_compile_cache() -> str:
 class Timing:
     seconds_per_call: float
     inner_repeats: int
-    compile_seconds: float = 0.0
-    cold: bool = False
 
 
 def block(x):
@@ -50,19 +52,18 @@ def block(x):
 def time_fn(fn: Callable[[], object], *, min_measure_s: float = 0.02,
             max_inner: int = 1000) -> Timing:
     """Calibrated timing of `fn` (which must block on its own result)."""
-    t0 = time.perf_counter()
-    fn()                                   # warmup / compile
-    compile_s = time.perf_counter() - t0
+    with span("timing.warmup"):
+        fn()
 
     inner = 1
     while True:
-        t0 = time.perf_counter()
-        for _ in range(inner):
-            fn()
-        dt = time.perf_counter() - t0
+        with span("timing.round", inner=inner):
+            t0 = time.perf_counter()
+            for _ in range(inner):
+                fn()
+            dt = time.perf_counter() - t0
         if dt >= min_measure_s or inner >= max_inner:
-            return Timing(seconds_per_call=dt / inner, inner_repeats=inner,
-                          compile_seconds=compile_s, cold=compile_s > 10 * dt)
+            return Timing(seconds_per_call=dt / inner, inner_repeats=inner)
         inner = min(max_inner, max(inner * 2,
                                    int(inner * min_measure_s / max(dt, 1e-9))))
 

@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core.duet import DuetPair
 from repro.core.rmit import Invocation, SuitePlan
+from repro.obs.spans import span
 
 
 @dataclass
@@ -677,6 +678,13 @@ class ExecutionEngine:
     # ------------------------------------------------------------ realtime
     def _run_realtime(self, plan: SuitePlan,
                       observer: Optional[EngineObserver]) -> EngineReport:
+        """The thread-pool loop, as the span ``engine.run``; each attempt
+        of an invocation is a span ``engine.execute`` in its worker."""
+        with span("engine.run"):
+            return self._realtime_loop(plan, observer)
+
+    def _realtime_loop(self, plan: SuitePlan,
+                       observer: Optional[EngineObserver]) -> EngineReport:
         cfg, be = self.cfg, self.backend
         be.begin_run(cfg.parallelism)
         t_start = time.monotonic()
@@ -692,15 +700,18 @@ class ExecutionEngine:
         #                                  benchmark never succeeded at all
         hedged = skipped = timeouts = 0
 
-        def attempt(inv: Invocation, tries_left: int):
+        def attempt(inv: Invocation, tries_left: int, n: int = 0):
             """Returns (pairs_or_None, exception_or_None, started, ended).
             Per-benchmark accounting happens in the main loop — a hedge
             duplicate and its original race under first-success-wins, so
             neither a late nor an early failed duplicate may condemn a
-            benchmark whose other attempt succeeded."""
+            benchmark whose other attempt succeeded.  `n` counts the
+            retries before this attempt."""
             t0 = time.monotonic()
             try:
-                res = be.execute(inv)
+                with span("engine.execute", benchmark=inv.benchmark,
+                          attempt=n):
+                    res = be.execute(inv)
             except Exception as exc:
                 # benchmark timeouts are deterministic — re-running would
                 # burn another full timeout for the same outcome; only
@@ -708,7 +719,7 @@ class ExecutionEngine:
                 if tries_left > 0 and not isinstance(exc, TimeoutError):
                     with self._lock:
                         state["retries"] += 1
-                    return attempt(inv, tries_left - 1)
+                    return attempt(inv, tries_left - 1, n + 1)
                 return None, exc, t0, time.monotonic()
             t1 = time.monotonic()
             with self._lock:

@@ -44,6 +44,16 @@ per-tenant cost attribution), ``service/planner.py`` (plan decisions,
 infeasibility dumps), and ``cb/pipeline.py`` (commit spans, cache and
 selector hits, CI-width convergence).
 
+Two clocks, two lanes.  Simulated runs trace on the virtual clock
+(``RecordingTracer``, everything above).  Real executions trace on the
+profiler's clock (``repro.obs.spans``): ``span(name, **meta)`` is a
+``jax.profiler.TraceAnnotation`` that records only inside a profiler
+session, in the same trace as the device's program executions, around
+the realtime path's commit, engine run and invocation, duet pair and
+versions, and the timing harness's warm-up and calibration rounds;
+``compiles()`` counts the process's XLA backend compiles.  It has no
+switch of its own: the profiler session is the switch.
+
 Plumbing is a process-global context rather than threaded parameters:
 ``set_obs(Observability.recording())`` turns the sensors on for every
 engine/fleet/pipeline constructed afterwards, ``use_obs(...)`` scopes it
