@@ -51,8 +51,9 @@ profiler's clock (``repro.obs.spans``): ``span(name, **meta)`` is a
 session, in the same trace as the device's program executions, around
 the realtime path's commit, engine run and invocation, duet pair and
 versions, and the timing harness's warm-up and calibration rounds;
-``compiles()`` counts the process's XLA backend compiles.  It has no
-switch of its own: the profiler session is the switch.
+``compiles()`` counts the process's XLA backend compiles and
+``timed_invocations()`` the timing harness's hits and calibrations.  It
+has no switch of its own: the profiler session is the switch.
 
 Plumbing is a process-global context rather than threaded parameters:
 ``set_obs(Observability.recording())`` turns the sensors on for every

@@ -1,4 +1,4 @@
-"""The wall-clock lane: spans and a compile counter for real executions.
+"""The wall-clock lane: spans and counters for real executions.
 
 Simulated runs trace on the virtual clock (``RecordingTracer``).  Real
 executions trace here, on the clock of JAX's profiler, which the device's
@@ -25,7 +25,9 @@ The spans, from the outside in::
               duet.v1/<benchmark>, duet.v2/<benchmark>   each version
                 timing.warmup    time_fn's warm-up call
                 timing.round     one pass of time_fn's calibration loop,
-                                 meta inner; a version's last is measured
+                                 meta inner, and stored (the round ran at
+                                 the program's stored count); a version's
+                                 last is measured
         cb.analyze     the change analysis of the run's pairs
       cb.finalize      history records and cache fills
     cb.detect          RegressionDetector.scan
@@ -36,6 +38,13 @@ compiles and their seconds for the whole process, through a
 before and after a step.  The listener is registered when this module
 is imported into a process that has imported JAX, else by the first span
 or reading after JAX is; compiles before that are not counted.
+
+`timed_invocations` answers "did the harness calibrate": it counts the
+timing harness's invocations (``repro.core.timing.time_fn``) as hits,
+which measured in one round at their program's stored inner count, and
+calibrations, which did not (a program's first invocation, or one whose
+stored count fell short).  Read it before and after a step, like
+`compiles`.
 """
 from __future__ import annotations
 
@@ -50,6 +59,7 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _OFF = contextlib.nullcontext()
 _lock = threading.Lock()
 _compiles = [0, 0.0]            # count, seconds
+_timed = [0, 0]                 # hits, calibrations
 _listening = False
 
 
@@ -71,6 +81,19 @@ def compiles() -> Tuple[int, float]:
         _listen()
     with _lock:
         return _compiles[0], _compiles[1]
+
+
+def timed_invocations() -> Tuple[int, int]:
+    """Timing-harness invocations counted in this process so far: hits
+    and calibrations."""
+    with _lock:
+        return _timed[0], _timed[1]
+
+
+def count_timed(*, hit: bool) -> None:
+    """Count one timing-harness invocation (`timed_invocations`)."""
+    with _lock:
+        _timed[0 if hit else 1] += 1
 
 
 def _on_duration(event: str, seconds: float, **_) -> None:

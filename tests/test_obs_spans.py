@@ -17,7 +17,7 @@ from repro.core import rmit
 from repro.core.duet import DuetRunnable
 from repro.faas.backends import LocalDuetBackend
 from repro.obs import Observability, use_obs
-from repro.obs.spans import compiles, span
+from repro.obs.spans import compiles, span, timed_invocations
 
 
 def host_spans(trace_dir, prefix):
@@ -124,3 +124,51 @@ def test_a_simulated_commit_keeps_its_virtual_span_and_no_cb_spans(
     assert pipe._obs_clock == pytest.approx(run.wall_seconds)
     assert host_spans(tmp_path, ("cb.commit", "cb.prepare", "cb.suite_run",
                                  "cb.finalize")) == []
+
+
+def test_a_later_kernel_commit_starts_each_version_at_its_stored_count(
+        tmp_path):
+    """The small kernel suite, on the real clock: in the commit after the
+    first that measured, each version warms up once and starts at its
+    program's stored count, its last round reaches the 20-ms target (or
+    `max_inner`), a version measured in that one round is a hit, and
+    the counter counts each version once."""
+    kernel_bench = pytest.importorskip("benchmarks.kernel_bench")
+    suite = kernel_bench.KernelSuite(small=True)
+    names = suite.benchmark_names()
+    cfg = PipelineConfig(suite="kernels", provider="local", mode="selective",
+                         n_calls=3, repeats_per_call=1, parallelism=1,
+                         min_results=3)
+    pipe = Pipeline(suite, cfg)
+    base, head = two_commits(names)
+    again = Commit(commit_id="c2", index=2, parent="c1", timestamp_s=0.0,
+                   fingerprints={b: "z" for b in names}, touched=tuple(names))
+    pipe.run_commit(base)
+    pipe.run_commit(head)
+    h0, c0 = timed_invocations()
+    with jax.profiler.trace(str(tmp_path)):
+        run = pipe.run_commit(again)
+    h1, c1 = timed_invocations()
+    assert run.ran == names
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    spans = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(("duet.v", "timing."))]
+    versions = [s for s in spans if s[0].startswith("duet.v")]
+    assert len(versions) == 2 * 3 * len(names)
+    one_round = 0
+    for v in versions:
+        inner = [s for s in spans if v[1] <= s[1] and s[2] <= v[2]
+                 and s is not v]
+        names = [s[0] for s in inner]
+        assert names[0] == "timing.warmup" and len(names) >= 2, v
+        assert set(names[1:]) == {"timing.round"}, v
+        assert inner[1][3]["stored"]
+        assert not any(s[3]["stored"] for s in inner[2:])
+        last = inner[-1]
+        assert last[2] - last[1] >= 20e6 or last[3]["inner"] == 1000
+        one_round += len(names) == 2
+    assert h1 - h0 == one_round >= 1
+    assert (h1 - h0) + (c1 - c0) == len(versions)
